@@ -8,6 +8,7 @@ from __future__ import annotations
 from benchmarks.common import row
 from repro.apps import echo, reed_solomon, vr_witness
 from repro.core import analyze
+from repro.launch.compile_cache import enable_compile_cache
 from repro.net.stack import tcp_topology, udp_topology
 
 
@@ -62,4 +63,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

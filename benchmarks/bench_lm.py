@@ -8,6 +8,7 @@ import json
 import os
 
 from benchmarks.common import row
+from repro.launch.compile_cache import enable_compile_cache
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
 
@@ -34,4 +35,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from benchmarks.common import append_trajectory, row, time_call
 from repro.apps import echo
 from repro.core import control
+from repro.launch.compile_cache import enable_compile_cache
 from repro.mgmt.console import command_frame
 from repro.net import frames as F, rpc
 from repro.net.stack import UdpStack
@@ -71,4 +72,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

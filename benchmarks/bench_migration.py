@@ -13,6 +13,7 @@ import numpy as np
 
 from benchmarks.common import row, time_call
 from repro.core import control
+from repro.launch.compile_cache import enable_compile_cache
 from repro.net import eth, frames as F, ipv4, nat, tcp
 
 IP_C = F.ip("10.0.0.2")
@@ -96,4 +97,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

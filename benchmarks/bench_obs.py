@@ -31,9 +31,10 @@ import time
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import (append_trajectory, assert_no_host_callbacks,
-                               row)
+from benchmarks.common import append_trajectory, row
 from repro.apps import echo
+from repro.launch.certify import assert_no_host_callbacks
+from repro.launch.compile_cache import enable_compile_cache
 from repro.net import frames as F, rpc
 from repro.net.stack import UdpStack, udp_topology
 from repro.obs import postcard, series, slo
@@ -163,4 +164,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import row
 from repro.launch import hlo_walk
+from repro.launch.compile_cache import enable_compile_cache
 from repro.net import eth, frames as F, ipv4, tcp, udp
 
 IP_C, IP_S = F.ip("10.0.0.2"), F.ip("10.0.0.1")
@@ -62,4 +63,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

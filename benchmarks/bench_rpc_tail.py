@@ -33,10 +33,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import (append_trajectory, assert_no_host_callbacks,
-                               row)
+from benchmarks.common import append_trajectory, row
 from repro.apps import lm_server
 from repro.configs.serve_smoke import MAX_SEQ, MAX_SESSIONS, serve_config
+from repro.launch.certify import assert_no_host_callbacks
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model
 from repro.net import eth, frames as F, ipv4, rpc, udp
 from repro.net.stack import UdpStack, rpc_serve_topology
@@ -177,4 +178,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

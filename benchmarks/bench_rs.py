@@ -16,6 +16,7 @@ from benchmarks.common import (append_trajectory, hlo_traffic, row,
                                time_call)
 from repro.apps import reed_solomon
 from repro.kernels.rs_encode import ops as rs_ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import HBM_BW
 from repro.net import frames as F, rpc
 from repro.net.stack import UdpStack
@@ -68,4 +69,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -22,6 +22,7 @@ import numpy as np
 
 from benchmarks.common import append_trajectory, row
 from repro.apps import echo
+from repro.launch.compile_cache import enable_compile_cache
 from repro.net import frames as F, rpc
 from repro.net.stack import UdpStack
 
@@ -103,4 +104,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

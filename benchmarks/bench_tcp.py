@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import (append_trajectory, hlo_traffic, row,
                                time_call)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import HBM_BW
 from repro.net import eth, frames as F, ipv4, tcp
 
@@ -101,4 +102,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -21,6 +21,7 @@ import time
 import numpy as np
 
 from benchmarks.common import append_trajectory, row
+from repro.launch.compile_cache import enable_compile_cache
 from repro.net import frames as F
 from repro.net.stack import TcpStack
 from repro.netem import Link, LinkConfig, LinuxTcpClient, StackEndpoint, \
@@ -101,4 +102,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -28,6 +28,7 @@ from benchmarks.common import (append_trajectory, hlo_traffic, row,
                                time_call)
 from repro.apps import echo
 from repro.core.noc import chain_latency_ns
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import HBM_BW
 from repro.net import frames as F, rpc
 from repro.net.stack import UdpStack
@@ -95,4 +96,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

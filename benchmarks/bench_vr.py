@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from benchmarks.common import hlo_traffic, row, time_call
 from repro.apps import vr_witness
 from repro.core.noc import chain_latency_ns
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import HBM_BW
 from repro.net import frames as F, rpc
 from repro.net.stack import UdpStack
@@ -55,4 +56,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

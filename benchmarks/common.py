@@ -66,31 +66,6 @@ def row(name: str, us: float, derived: str) -> str:
     return line
 
 
-def assert_no_host_callbacks(fn: Callable, *args) -> None:
-    """Walk fn(*args)'s jaxpr (scan bodies included) and fail on any
-    host-touching primitive — the zero-host-sync certification from
-    tests/test_stream.py, shared by the benchmarks that gate on it."""
-    closed = jax.make_jaxpr(fn)(*args)
-    prims = set()
-
-    def walk(jaxpr):
-        for eq in jaxpr.eqns:
-            prims.add(eq.primitive.name)
-            for v in eq.params.values():
-                vs = v if isinstance(v, (tuple, list)) else (v,)
-                for s in vs:
-                    if isinstance(s, jax.core.ClosedJaxpr):
-                        walk(s.jaxpr)
-                    elif isinstance(s, jax.core.Jaxpr):
-                        walk(s)
-
-    walk(closed.jaxpr)
-    bad = prims & {"pure_callback", "io_callback", "debug_callback",
-                   "infeed", "outfeed", "device_put"}
-    if bad:
-        raise RuntimeError(f"compiled path touches the host: {bad}")
-
-
 def append_trajectory(path: str, entry: dict) -> None:
     """Append one timestamped result to a BENCH_*.json trajectory file.
     History is the point: every PR adds a point, nothing is overwritten.

@@ -5,6 +5,8 @@ from __future__ import annotations
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     from benchmarks import (bench_flexibility, bench_lm, bench_mgmt,
@@ -29,4 +31,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -1,9 +1,11 @@
 """LM serving application behind the Beehive stack.
 
 Requests arrive as RPC-over-UDP (MSG_LM_GENERATE):
-  payload = [session u32 | n_gen u16 | n_prompt u16 | prompt tokens u16...]
+  payload = [session u32 | n_gen u16 | n_prompt u16 | prompt tokens u32...]
 Reply:
-  payload = [session u32 | n_out u16 | tokens u16 ...]
+  payload = [session u32 | n_out u16 | tokens u32 ...]
+Tokens are 32-bit: published vocabularies outgrow 16 bits (qwen1.5's has
+151,936 entries).
 An error reply carries a sentinel in n_out (>= ERR_BASE) and no tokens.
 MSG_LM_RELEASE closes a session explicitly: payload = [session u32].
 
@@ -44,7 +46,7 @@ REQ_HLEN = 8           # session u32 | n_gen u16 | n_prompt u16
 REP_HLEN = 6           # session u32 | n_out u16
 
 # error sentinels carried in the reply's n_out field (a real reply can
-# never reach them: tokens are u16, so n_out tops out near payload_len/2)
+# never reach them: tokens are u32, so n_out tops out near payload_len/4)
 ERR_BASE = 0xFFF0
 ERR_BAD_REQUEST = 0xFFFF   # malformed / truncated request payload
 ERR_NO_SLOT = 0xFFFE       # engine full and eviction disabled
@@ -53,7 +55,7 @@ ERR_NO_SESSION = 0xFFFD    # unknown session (or no prompt to open one)
 
 def encode_request(session: int, n_gen: int, prompt: List[int]) -> bytes:
     return struct.pack("!IHH", session, n_gen, len(prompt)) + \
-        b"".join(struct.pack("!H", t) for t in prompt)
+        b"".join(struct.pack("!I", t) for t in prompt)
 
 
 def encode_release(session: int) -> bytes:
@@ -66,17 +68,17 @@ def decode_request(payload: bytes) -> Tuple[int, int, List[int], bool]:
     if len(payload) < REQ_HLEN:
         return 0, 0, [], False
     session, n_gen, n_prompt = struct.unpack("!IHH", payload[:REQ_HLEN])
-    end = REQ_HLEN + 2 * n_prompt
+    end = REQ_HLEN + 4 * n_prompt
     if end > len(payload):
         return session, n_gen, [], False
-    toks = list(struct.unpack(f"!{n_prompt}H", payload[REQ_HLEN:end])) \
+    toks = list(struct.unpack(f"!{n_prompt}I", payload[REQ_HLEN:end])) \
         if n_prompt else []
     return session, n_gen, toks, True
 
 
 def encode_reply(session: int, tokens: List[int]) -> bytes:
     return struct.pack("!IH", session, len(tokens)) + \
-        b"".join(struct.pack("!H", t) for t in tokens)
+        b"".join(struct.pack("!I", t) for t in tokens)
 
 
 def encode_error(session: int, code: int) -> bytes:
@@ -92,10 +94,10 @@ def decode_reply(payload: bytes) -> Tuple[int, List[int], bool]:
     session, n = struct.unpack("!IH", payload[:REP_HLEN])
     if n >= ERR_BASE:
         return session, [], True
-    end = REP_HLEN + 2 * n
+    end = REP_HLEN + 4 * n
     if end > len(payload):
         return session, [], False
-    toks = list(struct.unpack(f"!{n}H", payload[REP_HLEN:end])) if n else []
+    toks = list(struct.unpack(f"!{n}I", payload[REP_HLEN:end])) if n else []
     return session, toks, True
 
 
@@ -170,20 +172,23 @@ class LmServerApp:
 # ---------------------------------------------------------------------------
 # direct-attached serving: the device-resident LM tile
 #
-# The tile's state (cache / pos / last_tok / used / sess_ids) lives in the
-# compiled stack's state pytree, so `run_stream` threads it through the
-# lax.scan carry — a request arriving in batch i advances its session for
-# batch i+1 with zero host involvement.  Prompts are prefilled host-side
-# through the ordinary ServeEngine and adopted via `adopt_engine`.
+# The tile's state (params / cache / pos / last_tok / used / sess_ids)
+# lives in the compiled stack's state pytree, so `run_stream` threads it
+# through the lax.scan carry — a request arriving in batch i advances its
+# session for batch i+1 with zero host involvement.  Prompts are
+# prefilled host-side through the ordinary ServeEngine and adopted via
+# `adopt_engine`.
 
 
 @dataclasses.dataclass
 class LmTileDecl:
     """Binding for a `lm_serve` tile (passed to the compiler by node name,
-    like an AppDecl).  `state` is the template the tile init copies."""
+    like an AppDecl).  `state` is the template the tile init copies; it
+    holds the weights under ``"params"``, so they reach the compiled
+    stream program as arguments and are never folded into it as
+    constants."""
     name: str
     cfg: Any
-    params: Any
     max_sessions: int
     max_seq: int
     state: Dict[str, Any]
@@ -193,6 +198,7 @@ def make_tile(cfg, params, max_sessions: int = 4, max_seq: int = 64,
               name: str = "lm") -> LmTileDecl:
     from repro.models import model
     state = {
+        "params": params,
         "cache": model.init_cache(cfg, max_sessions, max_seq),
         "pos": jnp.zeros((max_sessions,), jnp.int32),
         "last_tok": jnp.zeros((max_sessions,), jnp.int32),
@@ -200,9 +206,8 @@ def make_tile(cfg, params, max_sessions: int = 4, max_seq: int = 64,
         "sess_ids": jnp.zeros((max_sessions,), jnp.uint32),
         "served": jnp.zeros((), jnp.int32),
     }
-    return LmTileDecl(name=name, cfg=cfg, params=params,
-                      max_sessions=max_sessions, max_seq=max_seq,
-                      state=state)
+    return LmTileDecl(name=name, cfg=cfg, max_sessions=max_sessions,
+                      max_seq=max_seq, state=state)
 
 
 def adopt_engine(tile_state: Dict[str, Any], engine: ServeEngine,
@@ -241,7 +246,7 @@ def tile_process(decl: LmTileDecl, st: Dict[str, Any], body, blen, active):
     error reply and advance nothing.
     """
     from repro.models import model
-    cfg, params, S = decl.cfg, decl.params, decl.max_seq
+    cfg, params, S = decl.cfg, st["params"], decl.max_seq
 
     sess = B.be32(body, 0)                              # (B,) uint32
     n_gen = B.be16(body, 4)
@@ -275,8 +280,8 @@ def tile_process(decl: LmTileDecl, st: Dict[str, Any], body, blen, active):
                   jnp.where(~hit, jnp.uint32(ERR_NO_SESSION),
                             jnp.uint32(ERR_NO_SLOT))))   # session out of room
     out = B.set_be16(out, 4, n_out)
-    out = B.set_be16(out, 6, jnp.where(valid, tok, jnp.uint32(0)))
-    out_blen = jnp.where(valid, REP_HLEN + 2, REP_HLEN).astype(blen.dtype)
+    out = B.set_be32(out, 6, jnp.where(valid, tok, jnp.uint32(0)))
+    out_blen = jnp.where(valid, REP_HLEN + 4, REP_HLEN).astype(blen.dtype)
 
     new_st = dict(st)
     new_st.update(cache=cache, pos=new_pos, last_tok=new_last,

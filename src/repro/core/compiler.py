@@ -613,6 +613,7 @@ class CompiledPipeline:
         first_reason = jnp.zeros((n,), jnp.int32)
         zero_reason = jnp.zeros((n,), jnp.int32)
         ok_of: Dict[str, jnp.ndarray] = {}
+        taken: Dict[str, jnp.ndarray] = {}     # src -> rows an out-edge took
         for node, spec, ctx, in_edges, trunk in self.stages:
             if not in_edges:                       # ingress / chain root
                 pred = jnp.ones((n,), bool)
@@ -632,6 +633,7 @@ class CompiledPipeline:
                     else:
                         hit = _match_pred(route, carrier, n)
                     pred = pred | (ok_of[src] & hit)
+                    taken[src] = taken.get(src, False) | hit
             carrier = dict(carrier)
             carrier["drop_reason"] = zero_reason   # tiles overwrite per row
             stage_len = carrier["length"]          # view before the tile
@@ -671,6 +673,23 @@ class CompiledPipeline:
                     enters.append(ctx.lat_cycles + q)
                     exits.append(ctx.lat_cycles + q + 1)
                     visits.append(pred)
+
+        # packets a routing stage passed but no out-edge took have no
+        # route: attributed to that stage (the ingress is exempt — the
+        # zero-length padding rows of a batch match no route there)
+        if count_drops or obs is not None:
+            for src, hit in taken.items():
+                i = self._index[src]
+                if not self.stages[i][3]:
+                    continue
+                lost = ok_of[src] & ~hit
+                if count_drops:
+                    drop_blocks[i] = drop_blocks[i] + telemetry.reason_counts(
+                        jnp.full((n,), reasons.NO_ROUTE, jnp.int32), lost,
+                        reasons.NUM_REASONS)
+                if obs is not None:
+                    first_reason = jnp.where((first_reason == 0) & lost,
+                                             reasons.NO_ROUTE, first_reason)
 
         # ---- fused telemetry: ONE stacked row write for the whole batch --
         # (the per-stage masked appends collapsed into a single

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import select_interpret
+
 NEG_INF = -1e30
 
 
@@ -66,8 +68,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, d_scr, acc_scr, *,
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
-                           bq: int = 256, bk: int = 256,
-                           interpret: bool = True):
+                           bq: int = 256, bk: int = 256):
     """q: (BH, S, hd); k, v: (BKV, S, hd) with BH = BKV * G.
     Returns (BH, S, hd)."""
     BH, S, hd = q.shape
@@ -81,20 +82,26 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
 
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
                                window=window, bq=bq, bk=bk, n_k=n_k)
-    return pl.pallas_call(
-        kernel,
-        grid=(BH, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, hd), lambda b, i, j, G=G: (b // G, j, 0)),
-            pl.BlockSpec((1, bk, hd), lambda b, i, j, G=G: (b // G, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, hd), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v)
+
+    def call(q, k, v, interpret):
+        return pl.pallas_call(
+            kernel,
+            grid=(BH, n_q, n_k),
+            in_specs=[
+                pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bk, hd),
+                             lambda b, i, j, G=G: (b // G, j, 0)),
+                pl.BlockSpec((1, bk, hd),
+                             lambda b, i, j, G=G: (b // G, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
+            scratch_shapes=[
+                pltpu.VMEM((bq,), jnp.float32),
+                pltpu.VMEM((bq,), jnp.float32),
+                pltpu.VMEM((bq, hd), jnp.float32),
+            ],
+            interpret=interpret,
+        )(q, k, v)
+
+    return select_interpret(call, q, k, v)

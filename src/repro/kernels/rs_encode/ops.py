@@ -24,7 +24,7 @@ def rs_encode(data, k: int = 8, p: int = 2, use_pallas: bool = True,
     """data: (k, N) uint8 -> parity (p, N) uint8 for RS(k+p, k)."""
     gm, bp = _mats(k, p)
     if use_pallas:
-        return rs_encode_pallas(data, jnp.asarray(bp), block=block)
+        return rs_encode_pallas(data, bp, block=block)
     return rs_encode_jnp(data, gm)
 
 

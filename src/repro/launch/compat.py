@@ -1,16 +1,9 @@
-"""Version-compat shims for the jax mesh/sharding API.
-
-The repo targets two generations of jax:
-
-  * newer jax: ``jax.make_mesh(..., axis_types=(jax.sharding.AxisType.Auto,
-    ...))`` and the ``jax.set_mesh(mesh)`` context manager;
-  * jax <= 0.4.x: ``jax.make_mesh`` has no ``axis_types`` kwarg,
-    ``jax.sharding.AxisType`` does not exist, and the context-mesh is
-    entered via the ``Mesh`` object itself.
+"""Mesh and ``shard_map`` construction for the installed JAX (0.9).
 
 Everything that builds a mesh (launch/mesh.py, the multi-device test
-subprocess, elastic-restore tests) goes through these two helpers so the
-suite stays green on either version.
+subprocess) or maps a body over one (net/shard.py, optim/compress.py)
+goes through these two helpers, so the axis types and the replication
+check are chosen in one place.
 """
 from __future__ import annotations
 
@@ -20,44 +13,13 @@ import jax
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """jax.make_mesh with Auto axis_types where the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                                 axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:
-            pass
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-
-
-def set_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh  # jax.sharding.Mesh is itself a context manager on 0.4.x
-
-
-def get_context_mesh():
-    """The ambient (context) mesh, or None when none is installed."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    from jax._src import mesh as mesh_lib  # jax 0.4.x: Mesh ctx manager
-    resources = getattr(mesh_lib, "thread_resources", None)
-    if resources is None:
-        return None
-    physical = resources.env.physical_mesh
-    return None if physical.empty else physical
+    """``jax.make_mesh`` with every axis of the Auto type."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def shard_map(body, *, mesh, in_specs, out_specs):
-    """jax.shard_map with replication checking off, on either API."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-    return legacy_sm(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with the replication check off."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

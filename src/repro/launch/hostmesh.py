@@ -3,13 +3,17 @@
 XLA only honours ``--xla_force_host_platform_device_count`` if it is set
 *before* the first jax import, so multi-device runs on a CPU box must
 happen in a subprocess with a prepared environment.  This module is the
-one place that pattern lives (extracted from tests/test_distributed.py):
+one place that pattern lives:
 
-  * :func:`forced_env` — a subprocess environment forcing N host devices;
+  * :func:`forced_env` — a subprocess environment forcing N host devices,
+    pinned to the CPU platform;
   * :func:`run_script` — run a python snippet under that environment,
     with a guard prologue that prints :data:`UNAVAILABLE` and exits 0
-    when the forcing did not take (e.g. an accelerator platform already
-    claimed the process) so callers can skip instead of fail.
+    when the forcing did not take, so tests can skip instead of fail.
+
+The child never opens an accelerator: a chip belongs to one process, and
+a parent that has touched JAX already holds it.  Work that needs real
+devices runs in the parent's own process.
 
 No jax import here: importing this module never touches device state, so
 a parent process can use it before (or without) initialising jax.
@@ -37,8 +41,10 @@ if jax.device_count() < {n}:
 def forced_env(devices: int, base_env: Optional[dict] = None) -> dict:
     """A copy of ``base_env`` (default: os.environ) with ``XLA_FLAGS``
     forcing ``devices`` host platform devices (any prior forcing flag is
-    replaced) and ``PYTHONPATH`` including ``src``."""
+    replaced), ``JAX_PLATFORMS=cpu`` and ``PYTHONPATH`` including
+    ``src``."""
     env = dict(os.environ if base_env is None else base_env)
+    env["JAX_PLATFORMS"] = "cpu"
     flags = [p for p in env.get("XLA_FLAGS", "").split()
              if not p.startswith(FLAG)]
     flags.append(f"{FLAG}={devices}")

@@ -27,7 +27,9 @@ from repro.sharding import Policy, SINGLE
 # init
 
 
+@partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
+    """Random weights from ``key``, built as one compiled program."""
     cfg.validate()
     keys = jax.random.split(key, 4)
     units = {}

@@ -26,8 +26,8 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.launch.compat import shard_map
 from repro.launch.mesh import make_mesh_for
@@ -103,6 +103,8 @@ class ShardedStream:
             self.shards, model_parallel=1)
         self.policy = Policy(dp=("data",), enabled=True)
         spec = self.policy.batch()
+        # leading shard axis split over the mesh: shard s lives on device s
+        self.sharding = NamedSharding(self.mesh, spec)
 
         def body(state, payloads, lengths):
             st = jax.tree.map(lambda x: x[0], state)
@@ -115,10 +117,13 @@ class ShardedStream:
                                   out_specs=(spec, spec))
 
     def init_state(self):
-        """One replica of the stack state per shard (leading S axis)."""
+        """One replica of the stack state per shard (leading S axis),
+        each placed straight on its shard's device."""
         st = self.stack.init_state()
         return jax.tree.map(
-            lambda x: jnp.stack([x] * self.shards), st)
+            lambda x: jax.device_put(
+                np.broadcast_to(np.asarray(x), (self.shards,) + x.shape),
+                self.sharding), st)
 
     def make_arena(self, n_batches: int, batch: int,
                    max_len: int) -> ShardedFrameArena:
@@ -127,8 +132,8 @@ class ShardedStream:
     def run_stream(self, state, payloads, lengths):
         """All shards stream their (N, B, L) partition under one
         dispatch.  Returns (state', outs) with leading shard axes."""
-        return self._sharded(state, jnp.asarray(payloads),
-                             jnp.asarray(lengths))
+        return self._sharded(state, jax.device_put(payloads, self.sharding),
+                             jax.device_put(lengths, self.sharding))
 
     def stream_fn(self):
         """Jitted entry point with the state carry donated, matching the

@@ -52,6 +52,10 @@ APP_NO_SLOT = 15       # session table full / session out of room
 # ipinip_decap
 IPIP_BAD = 16          # outer header not a decapsulatable IP-in-IP frame
 
+# executor: a routing stage passed the packet but no out-edge took it
+# (e.g. a UDP datagram to a port no tile is bound to)
+NO_ROUTE = 17
+
 NUM_REASONS = 24       # fixed table width (wire format; room to grow)
 
 NAMES = {
@@ -63,7 +67,7 @@ NAMES = {
     TCP_NO_CONN: "tcp_no_conn",
     APP_BAD_REQ: "app_bad_req", APP_NO_SESSION: "app_no_session",
     APP_NO_SLOT: "app_no_slot",
-    IPIP_BAD: "ipip_bad",
+    IPIP_BAD: "ipip_bad", NO_ROUTE: "no_route",
 }
 
 

@@ -38,6 +38,7 @@ class ServeEngine:
         self.pos = jnp.zeros((max_sessions,), jnp.int32)
         self.used = np.zeros((max_sessions,), bool)
         self.last_tok = jnp.zeros((max_sessions,), jnp.int32)
+        self._prefill = jax.jit(self._prefill_impl)
         self._decode = jax.jit(self._decode_impl)
 
     # ---- session lifecycle -----------------------------------------------
@@ -63,9 +64,8 @@ class ServeEngine:
         batch = {"tokens": jnp.asarray(prompt_tokens)[None, :]}
         if extras:
             batch.update({k: jnp.asarray(v)[None] for k, v in extras.items()})
-        logits, pcache = model.prefill(self.cfg, self.params, batch,
-                                       self.policy)
-        tok = model.greedy_token(self.cfg, logits)[0]
+        tok, pcache = self._prefill(self.params, batch)
+        tok = tok[0]
         P = prompt_tokens.shape[0]
         self._install_cache(sid, pcache, P)
         self.pos = self.pos.at[sid].set(P)
@@ -115,6 +115,10 @@ class ServeEngine:
 
             self.cache["rem"][j] = jax.tree_util.tree_map_with_path(
                 merge_rem, self.cache["rem"][j], pcache["rem"][j])
+
+    def _prefill_impl(self, params, batch):
+        logits, pcache = model.prefill(self.cfg, params, batch, self.policy)
+        return model.greedy_token(self.cfg, logits), pcache
 
     # ---- batched decode ---------------------------------------------------
     def _decode_impl(self, params, cache, tok, pos):

@@ -51,9 +51,8 @@ class Policy:
     def axis_size(self, name: Optional[str]) -> int:
         if not self.enabled or name is None:
             return 1
-        from repro.launch.compat import get_context_mesh
-        mesh = get_context_mesh()
-        if mesh is None or mesh.empty:  # pragma: no cover - defensive
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
             return 1
         return dict(mesh.shape).get(name, 1)
 

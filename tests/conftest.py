@@ -3,10 +3,9 @@
 `forced_host_mesh` is the one way tests get a multi-device mesh on a CPU
 box: XLA only honours ``--xla_force_host_platform_device_count`` before
 the first jax import, so the snippet runs in a subprocess with a
-prepared environment (repro.launch.hostmesh).  When the platform refuses
-the forcing — an accelerator already claimed the process — the run is
-*skipped* with a clear message instead of failing, so the suite stays
-green on every backend.
+prepared environment (repro.launch.hostmesh), pinned to the CPU.  When
+the forcing does not take, the run is *skipped* with a clear message
+instead of failing.
 """
 import os
 

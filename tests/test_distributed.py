@@ -13,13 +13,13 @@ _SCRIPT = r"""
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.compat import make_mesh, set_mesh
+from repro.launch.compat import make_mesh
 
 mesh = make_mesh((2, 4), ("pod", "data"))
 
 # --- compressed cross-pod psum -------------------------------------------
 from repro.optim import compress
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     g = jax.random.normal(jax.random.key(0), (64,))
     r = jnp.zeros((64,))
     out, new_r = compress.compressed_psum_pod({"w": g}, {"w": r}, mesh)
@@ -48,7 +48,7 @@ single = make_train_step(cfg, Policy())
 p1, o1, m1 = jax.jit(single)(params, opt, batch)
 
 mesh2 = make_mesh((4, 2), ("data", "model"))
-with set_mesh(mesh2):
+with jax.set_mesh(mesh2):
     pol = make_policy(mesh2)
     sharded = make_train_step(cfg, pol)
     p2, o2, m2 = jax.jit(sharded)(params, opt, batch)

@@ -121,6 +121,13 @@ def test_truncated_request_and_reply_decode_not_ok():
     assert decode_reply(rep) == (7, [1, 2, 3], True)
 
 
+def test_codec_carries_tokens_beyond_16_bits():
+    """Published vocabularies outgrow u16 (qwen1.5: 151,936 tokens)."""
+    toks = [151935, 65536, 3]
+    assert decode_request(encode_request(7, 1, toks)) == (7, 1, toks, True)
+    assert decode_reply(encode_reply(7, toks)) == (7, toks, True)
+
+
 def test_error_reply_roundtrip():
     rep = lm_server.encode_error(9, ERR_NO_SESSION)
     assert decode_reply(rep) == (9, [], True)

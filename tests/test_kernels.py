@@ -152,6 +152,33 @@ def test_mamba_scan_state_carries_across_blocks():
 
 
 # ---------------------------------------------------------------------------
+# interpret-mode selection
+
+
+_GM = gf.generator_matrix(8, 2)
+_RS_BP = gf.bitplane_matrix(_GM)
+_BYTES = np.arange(8 * 4096, dtype=np.uint32).astype(np.uint8)
+
+
+@pytest.mark.parametrize("kernel,ref,args", [
+    (lambda d: rs_encode_pallas(d, _RS_BP),
+     lambda d: rs_encode_np(d, _GM), (_BYTES.reshape(8, -1),)),
+    (checksum_pallas,
+     lambda d, n: checksum_ref(jnp.asarray(d), jnp.asarray(n)),
+     (_BYTES[:16 * 128].reshape(16, -1), np.arange(16, dtype=np.int32) * 8)),
+], ids=["rs_encode", "checksum"])
+def test_kernel_interprets_on_cpu_and_compiles_for_tpu(kernel, ref, args):
+    """The CPU runs the Pallas interpreter; a program lowered for a TPU
+    holds the compiled kernel — interpret mode is never picked there."""
+    fn = jax.jit(kernel)
+    assert "tpu_custom_call" not in fn.lower(*args).as_text()
+    np.testing.assert_array_equal(np.asarray(fn(*args)),
+                                  np.asarray(ref(*args)))
+    tpu = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in tpu
+
+
+# ---------------------------------------------------------------------------
 # ops-level wrappers (model layout)
 
 

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.apps import echo
 from repro.core import control
+from repro.launch.certify import assert_no_host_callbacks
 from repro.mgmt.console import MgmtConsole, command_frame, parse_response
 from repro.net import frames as F, rpc
 from repro.net.stack import UdpStack, rpc_serve_topology
@@ -159,24 +160,8 @@ def test_recorder_and_histos_add_no_host_callbacks():
     p, l = jnp.asarray(arena.payload), jnp.asarray(arena.length)
 
     fn = lambda s, pp, ll: stack.run_stream(s, pp, ll)
-    closed = jax.make_jaxpr(fn)(st, p, l)
-    prims = set()
-
-    def walk(jaxpr):
-        for eq in jaxpr.eqns:
-            prims.add(eq.primitive.name)
-            for v in eq.params.values():
-                vs = v if isinstance(v, (tuple, list)) else (v,)
-                for s in vs:
-                    if isinstance(s, jax.core.ClosedJaxpr):
-                        walk(s.jaxpr)
-                    elif isinstance(s, jax.core.Jaxpr):
-                        walk(s)
-
-    walk(closed.jaxpr)
+    prims = assert_no_host_callbacks(fn, st, p, l)
     assert "scan" in prims
-    assert not prims & {"pure_callback", "io_callback", "debug_callback",
-                        "infeed", "outfeed", "device_put"}
 
     hlo = jax.jit(fn).lower(st, p, l).compile().as_text().lower()
     assert "infeed" not in hlo and "outfeed" not in hlo

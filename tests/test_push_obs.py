@@ -13,13 +13,13 @@ Acceptance coverage:
   * the mirror's extra egress frames and the watchdog's alert path are
     deadlock-analyzed (data + ctrl NoCs).
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.apps import echo
 from repro.core import control, deadlock
+from repro.launch.certify import assert_no_host_callbacks
 from repro.mgmt.console import MgmtConsole
 from repro.net import frames as F, rpc
 from repro.net.stack import UdpStack, udp_topology
@@ -292,24 +292,8 @@ def test_push_obs_adds_no_host_callbacks(push_stack):
     arena.fill([echo_frame(5000 + i) for i in range(4)])
     p, l = jnp.asarray(arena.payload), jnp.asarray(arena.length)
 
-    closed = jax.make_jaxpr(stack.run_stream)(state, p, l)
-    prims = set()
-
-    def walk(jaxpr):
-        for eq in jaxpr.eqns:
-            prims.add(eq.primitive.name)
-            for v in eq.params.values():
-                vs = v if isinstance(v, (tuple, list)) else (v,)
-                for s in vs:
-                    if isinstance(s, jax.core.ClosedJaxpr):
-                        walk(s.jaxpr)
-                    elif isinstance(s, jax.core.Jaxpr):
-                        walk(s)
-
-    walk(closed.jaxpr)
+    prims = assert_no_host_callbacks(stack.run_stream, state, p, l)
     assert "scan" in prims
-    assert not prims & {"pure_callback", "io_callback", "debug_callback",
-                        "infeed", "outfeed", "device_put"}
 
 
 def test_mirror_and_alert_paths_are_deadlock_analyzed(push_stack):

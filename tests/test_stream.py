@@ -18,6 +18,7 @@ import pytest
 
 from repro.apps import echo
 from repro.core.compiler import StackCompiler
+from repro.launch.certify import assert_no_host_callbacks
 from repro.net import frames as F, ipinip, rpc, tcp
 from repro.net.stack import (TcpStack, UdpStack, ipinip_udp_topology,
                              udp_topology)
@@ -177,24 +178,8 @@ def test_run_stream_zero_host_transfers():
     p, l = jnp.asarray(arena.payload), jnp.asarray(arena.length)
 
     fn = lambda st, pp, ll: stack.run_stream(st, pp, ll)
-    closed = jax.make_jaxpr(fn)(state, p, l)
-    prims = set()
-
-    def walk(jaxpr):
-        for eq in jaxpr.eqns:
-            prims.add(eq.primitive.name)
-            for v in eq.params.values():
-                vs = v if isinstance(v, (tuple, list)) else (v,)
-                for s in vs:
-                    if isinstance(s, jax.core.ClosedJaxpr):
-                        walk(s.jaxpr)
-                    elif isinstance(s, jax.core.Jaxpr):
-                        walk(s)
-
-    walk(closed.jaxpr)
+    prims = assert_no_host_callbacks(fn, state, p, l)
     assert "scan" in prims                 # the N batches are ONE loop
-    assert not prims & {"pure_callback", "io_callback", "debug_callback",
-                        "infeed", "outfeed", "device_put"}
 
     hlo = jax.jit(fn).lower(state, p, l).compile().as_text()
     low = hlo.lower()
