@@ -1,0 +1,7 @@
+"""Median latency over every request due in the window, from its
+scheduled arrival to its reply on the host, in ms."""
+from bench.readers import percentile_ms
+
+
+def read(ctx):
+    return percentile_ms(ctx, 50)
