@@ -12,7 +12,7 @@ PYTHONPATH := src:.$(if $(PYTHONPATH),:$(PYTHONPATH),)
 export PYTHONPATH
 
 .PHONY: test-fast test bench bench-mgmt bench-tcp-loss bench-stream \
-        bench-rpc-tail bench-obs bench-shard lint-reasons
+        bench-rpc-tail bench-shard lint-reasons
 
 test-fast:
 	$(PY) -m pytest -q -m "not slow"
@@ -49,13 +49,6 @@ bench-stream:
 # baseline; APPENDS a trajectory entry to BENCH_rpc_tail.json
 bench-rpc-tail:
 	$(PY) benchmarks/bench_rpc_tail.py
-
-# observability gate: pull (flight recorder @1/64 + histograms) AND push
-# (postcards + series ring + SLO watchdog) must each stay within 10% of
-# the telemetry-only run_stream baseline, with zero host callbacks in
-# the scanned region; APPENDS to BENCH_obs.json
-bench-obs:
-	$(PY) benchmarks/bench_obs.py
 
 # sharded-dataplane gate: RSS-replicated stack under shard_map on a
 # host-simulated 8-device mesh — certified (no collectives, no host

@@ -10,7 +10,7 @@ from repro.launch.compile_cache import enable_compile_cache
 
 def main() -> None:
     from benchmarks import (bench_flexibility, bench_lm, bench_mgmt,
-                            bench_migration, bench_obs, bench_rs,
+                            bench_migration, bench_rs,
                             bench_shard, bench_stream, bench_tcp,
                             bench_tcp_loss, bench_udp_echo, bench_vr,
                             bench_resources)
@@ -18,7 +18,7 @@ def main() -> None:
     failures = 0
     for mod in (bench_flexibility, bench_udp_echo, bench_stream, bench_tcp,
                 bench_tcp_loss, bench_rs, bench_vr, bench_migration,
-                bench_mgmt, bench_obs, bench_shard, bench_resources,
+                bench_mgmt, bench_shard, bench_resources,
                 bench_lm):
         try:
             mod.run()

@@ -26,7 +26,13 @@ Compilation steps:
      the compiled pipeline appends one counter row per batch per node
      (packets-in, drops, a compile-time NoC latency estimate from
      ``noc.chain_latency_cycles``) — diagnostics come for free on every
-     path.
+     path;
+  6. the executor names its work for a device trace with
+     ``jax.named_scope`` (HLO metadata only, no computation): each stage
+     under ``stage/<node>``, the observability blocks under
+     ``obs/counters``, ``obs/drops``, ``obs/recorder``, ``obs/series``,
+     ``obs/postcard`` and ``obs/watchdog``, the management commit under
+     ``mgmt/commit``.
 
 Tile function contract::
 
@@ -615,64 +621,75 @@ class CompiledPipeline:
         ok_of: Dict[str, jnp.ndarray] = {}
         taken: Dict[str, jnp.ndarray] = {}     # src -> rows an out-edge took
         for node, spec, ctx, in_edges, trunk in self.stages:
-            if not in_edges:                       # ingress / chain root
-                pred = jnp.ones((n,), bool)
-            else:
-                pred = jnp.zeros((n,), bool)
-                for src, route in in_edges:
-                    tname = f"{src}:{route.match}"
-                    if (route.key is not None and route.match in _MATCH_FIELD
-                            and routes_rt is not None
-                            and tname in routes_rt):
-                        # live CAM lookup: the control plane can rewrite
-                        # this table between batches (paper §4.2)
-                        field = carrier["meta"][_MATCH_FIELD[route.match]]
-                        nxt = routes_rt[tname].lookup(
-                            field.astype(jnp.int32))
-                        hit = nxt == self._index[node.name]
-                    else:
-                        hit = _match_pred(route, carrier, n)
-                    pred = pred | (ok_of[src] & hit)
-                    taken[src] = taken.get(src, False) | hit
-            carrier = dict(carrier)
-            carrier["drop_reason"] = zero_reason   # tiles overwrite per row
-            stage_len = carrier["length"]          # view before the tile
-            state, carrier, ok = spec.fn(state, carrier, pred, ctx)
-            ok_of[node.name] = pred & ok if ok is not None else pred
-            if spec.alive:
-                if trunk:      # gates all traffic: alive = arrived & ok
-                    carrier["alive"] = ok_of[node.name]
-                else:          # branch tile: judge only its own packets
-                    prev = carrier.get("alive", jnp.ones((n,), bool))
-                    carrier["alive"] = jnp.where(pred, ok_of[node.name],
-                                                 prev)
+            # each stage's work under its own scope, stage/<node>, in the
+            # HLO metadata (and so in a device trace); scopes change no
+            # computation
+            with jax.named_scope(f"stage/{node.name}"):
+                if not in_edges:                       # ingress / chain root
+                    pred = jnp.ones((n,), bool)
+                else:
+                    pred = jnp.zeros((n,), bool)
+                    for src, route in in_edges:
+                        tname = f"{src}:{route.match}"
+                        if (route.key is not None
+                                and route.match in _MATCH_FIELD
+                                and routes_rt is not None
+                                and tname in routes_rt):
+                            # live CAM lookup: the control plane can rewrite
+                            # this table between batches (paper §4.2)
+                            field = carrier["meta"][_MATCH_FIELD[route.match]]
+                            nxt = routes_rt[tname].lookup(
+                                field.astype(jnp.int32))
+                            hit = nxt == self._index[node.name]
+                        else:
+                            hit = _match_pred(route, carrier, n)
+                        pred = pred | (ok_of[src] & hit)
+                        taken[src] = taken.get(src, False) | hit
+                carrier = dict(carrier)
+                carrier["drop_reason"] = zero_reason  # tiles set per row
+                stage_len = carrier["length"]          # view before the tile
+                state, carrier, ok = spec.fn(state, carrier, pred, ctx)
+                ok_of[node.name] = pred & ok if ok is not None else pred
+                if spec.alive:
+                    if trunk:      # gates all traffic: alive = arrived & ok
+                        carrier["alive"] = ok_of[node.name]
+                    else:          # branch tile: judge only its own packets
+                        prev = carrier.get("alive", jnp.ones((n,), bool))
+                        carrier["alive"] = jnp.where(pred, ok_of[node.name],
+                                                     prev)
             if count_nodes:
-                pkts_in.append(pred.sum(dtype=jnp.int32))
-                drops.append((pred & ~ok_of[node.name]).sum(dtype=jnp.int32))
-                bytes_l.append(jnp.where(pred, stage_len,
-                                         0).sum().astype(jnp.int32))
+                with jax.named_scope("obs/counters"):
+                    pkts_in.append(pred.sum(dtype=jnp.int32))
+                    drops.append(
+                        (pred & ~ok_of[node.name]).sum(dtype=jnp.int32))
+                    bytes_l.append(jnp.where(pred, stage_len,
+                                             0).sum().astype(jnp.int32))
             if count_drops or obs is not None:
-                # drop attribution: hard drops (arrived & failed) plus
-                # soft drops (tile set a reason but kept the packet alive,
-                # e.g. an app error reply); hard drops with no tile-
-                # supplied code fall back to UNSPEC
-                reason = carrier["drop_reason"]
-                hard = pred & ~ok_of[node.name]
-                counted = hard | (pred & (reason > 0))
-                reason = jnp.where(counted & (reason == 0),
-                                   reasons.UNSPEC, reason)
-                if count_drops:
-                    drop_blocks.append(telemetry.reason_counts(
-                        reason, counted, reasons.NUM_REASONS))
+                with jax.named_scope("obs/drops"):
+                    # drop attribution: hard drops (arrived & failed) plus
+                    # soft drops (tile set a reason but kept the packet
+                    # alive, e.g. an app error reply); hard drops with no
+                    # tile-supplied code fall back to UNSPEC
+                    reason = carrier["drop_reason"]
+                    hard = pred & ~ok_of[node.name]
+                    counted = hard | (pred & (reason > 0))
+                    reason = jnp.where(counted & (reason == 0),
+                                       reasons.UNSPEC, reason)
+                    if count_drops:
+                        drop_blocks.append(telemetry.reason_counts(
+                            reason, counted, reasons.NUM_REASONS))
                 if obs is not None:
-                    first_reason = jnp.where(
-                        (first_reason == 0) & counted, reason, first_reason)
-                    # per-frame stage occupancy proxy: static NoC latency
-                    # estimate + arrival-queue position within the batch
-                    q = jnp.cumsum(pred.astype(jnp.int32)) - 1
-                    enters.append(ctx.lat_cycles + q)
-                    exits.append(ctx.lat_cycles + q + 1)
-                    visits.append(pred)
+                    with jax.named_scope("obs/recorder"):
+                        first_reason = jnp.where(
+                            (first_reason == 0) & counted, reason,
+                            first_reason)
+                        # per-frame stage occupancy proxy: static NoC
+                        # latency estimate + arrival-queue position within
+                        # the batch
+                        q = jnp.cumsum(pred.astype(jnp.int32)) - 1
+                        enters.append(ctx.lat_cycles + q)
+                        exits.append(ctx.lat_cycles + q + 1)
+                        visits.append(pred)
 
         # packets a routing stage passed but no out-edge took have no
         # route: attributed to that stage (the ingress is exempt — the
@@ -682,14 +699,18 @@ class CompiledPipeline:
                 i = self._index[src]
                 if not self.stages[i][3]:
                     continue
-                lost = ok_of[src] & ~hit
-                if count_drops:
-                    drop_blocks[i] = drop_blocks[i] + telemetry.reason_counts(
-                        jnp.full((n,), reasons.NO_ROUTE, jnp.int32), lost,
-                        reasons.NUM_REASONS)
+                with jax.named_scope("obs/drops"):
+                    lost = ok_of[src] & ~hit
+                    if count_drops:
+                        drop_blocks[i] = drop_blocks[i] + \
+                            telemetry.reason_counts(
+                                jnp.full((n,), reasons.NO_ROUTE, jnp.int32),
+                                lost, reasons.NUM_REASONS)
                 if obs is not None:
-                    first_reason = jnp.where((first_reason == 0) & lost,
-                                             reasons.NO_ROUTE, first_reason)
+                    with jax.named_scope("obs/recorder"):
+                        first_reason = jnp.where(
+                            (first_reason == 0) & lost, reasons.NO_ROUTE,
+                            first_reason)
 
         # ---- fused telemetry: ONE stacked row write for the whole batch --
         # (the per-stage masked appends collapsed into a single
@@ -697,127 +718,137 @@ class CompiledPipeline:
         # *through the previous batch* — the batch's own row lands when it
         # completes, like a telemetry DMA at pipeline egress)
         if count_nodes:
-            rows = telemetry.counter_rows(
-                telem["step"], jnp.stack(pkts_in), jnp.stack(drops),
-                self._lat_cycles, self._node_idx)
-            telem["nodes"] = telemetry.append_stacked(telem["nodes"], rows)
+            with jax.named_scope("obs/counters"):
+                rows = telemetry.counter_rows(
+                    telem["step"], jnp.stack(pkts_in), jnp.stack(drops),
+                    self._lat_cycles, self._node_idx)
+                telem["nodes"] = telemetry.append_stacked(telem["nodes"],
+                                                          rows)
         if count_drops and drop_blocks:
             # ONE fused (num_nodes, NUM_REASONS) add per batch — same
             # egress-DMA discipline as the counter rows above, so DROP_READ
             # serves totals *through the previous batch*
-            telem["drops"] = telem["drops"] + jnp.stack(drop_blocks)
+            with jax.named_scope("obs/drops"):
+                telem["drops"] = telem["drops"] + jnp.stack(drop_blocks)
 
         # ---- flight recorder + latency histograms (device-resident) ------
         if obs is not None and visits:
-            nstages = len(self.stages)
-            E = jnp.stack(enters, axis=1)              # (B, nstages)
-            X = jnp.stack(exits, axis=1)
-            V = jnp.stack(visits, axis=1)              # (B, nstages) bool
-            en = (obs["ctrl"]["enable"] != 0)
-            en_i = en.astype(jnp.int32)
-            # per-stage occupancy (queue depth seen) + end-to-end rows
-            occ = X - self._lat_cycles[None, :]
-            hrows = [flight.bucket_counts(occ[:, i], V[:, i])
-                     for i in range(nstages)]
-            e2e = jnp.where(V, X, 0).max(axis=1) - E[:, 0]
-            hrows.append(flight.bucket_counts(e2e, V[:, 0]))
-            obs["histo"] = obs["histo"] + jnp.stack(hrows) * en_i
-            # sampled per-frame trace rows, ONE fused ring append per batch
-            fid = obs["frame_ctr"] + jnp.arange(n, dtype=jnp.int32)
-            sampled = flight.sample_mask(obs["ctrl"], fid)
-            bitmap = jnp.sum(
-                jnp.left_shift(V.astype(jnp.int32),
-                               jnp.arange(nstages, dtype=jnp.int32)[None, :]),
-                axis=1)
-            stepcol = jnp.broadcast_to(telem["step"], (n,))
-            trow = jnp.concatenate(
-                [fid[:, None], stepcol[:, None], bitmap[:, None],
-                 first_reason[:, None],
-                 jnp.stack([E, X], axis=2).reshape(n, 2 * nstages)], axis=1)
-            obs["trace"] = telemetry.append(obs["trace"], trow, sampled)
-            obs["frame_ctr"] = obs["frame_ctr"] + n
-            telem["obs"] = obs
+            with jax.named_scope("obs/recorder"):
+                nstages = len(self.stages)
+                E = jnp.stack(enters, axis=1)              # (B, nstages)
+                X = jnp.stack(exits, axis=1)
+                V = jnp.stack(visits, axis=1)              # (B, nstages) bool
+                en = (obs["ctrl"]["enable"] != 0)
+                en_i = en.astype(jnp.int32)
+                # per-stage occupancy (queue depth seen) + end-to-end rows
+                occ = X - self._lat_cycles[None, :]
+                hrows = [flight.bucket_counts(occ[:, i], V[:, i])
+                         for i in range(nstages)]
+                e2e = jnp.where(V, X, 0).max(axis=1) - E[:, 0]
+                hrows.append(flight.bucket_counts(e2e, V[:, 0]))
+                obs["histo"] = obs["histo"] + jnp.stack(hrows) * en_i
+                # sampled per-frame trace rows, ONE fused ring append per batch
+                fid = obs["frame_ctr"] + jnp.arange(n, dtype=jnp.int32)
+                sampled = flight.sample_mask(obs["ctrl"], fid)
+                bitmap = jnp.sum(
+                    jnp.left_shift(
+                        V.astype(jnp.int32),
+                        jnp.arange(nstages, dtype=jnp.int32)[None, :]),
+                    axis=1)
+                stepcol = jnp.broadcast_to(telem["step"], (n,))
+                trow = jnp.concatenate(
+                    [fid[:, None], stepcol[:, None], bitmap[:, None],
+                     first_reason[:, None],
+                     jnp.stack([E, X], axis=2).reshape(n, 2 * nstages)],
+                    axis=1)
+                obs["trace"] = telemetry.append(obs["trace"], trow, sampled)
+                obs["frame_ctr"] = obs["frame_ctr"] + n
+                telem["obs"] = obs
 
             # ---- push-mode observability (paper-adjacent INT postcards,
             # series ring, SLO watchdog — repro.obs.{series,postcard,slo})
             if "series" in telem and count_nodes:
-                # per-stage TCP retransmission totals (tcp_rx row only):
-                # stored cumulatively, so the window delta falls out of
-                # the series' cum-prev subtraction like the other metrics
-                retx_col = jnp.zeros((nstages,), jnp.int32)
-                ccs = state.get("conn")
-                ccs = ccs.get("cc") if isinstance(ccs, dict) else None
-                if ccs is not None and "tcp_rx" in self._index:
-                    total = (ccs["retx_fast"]
-                             + ccs["retx_timer"]).sum().astype(jnp.int32)
-                    retx_col = retx_col.at[self._index["tcp_rx"]].set(total)
-                telem["series"] = series.update(
-                    telem["series"], jnp.stack(pkts_in), jnp.stack(drops),
-                    jnp.stack(bytes_l), retx_col, obs["histo"])
+                with jax.named_scope("obs/series"):
+                    # per-stage TCP retransmission totals (tcp_rx row only):
+                    # stored cumulatively, so the window delta falls out of
+                    # the series' cum-prev subtraction like the other metrics
+                    retx_col = jnp.zeros((nstages,), jnp.int32)
+                    ccs = state.get("conn")
+                    ccs = ccs.get("cc") if isinstance(ccs, dict) else None
+                    if ccs is not None and "tcp_rx" in self._index:
+                        total = (ccs["retx_fast"]
+                                 + ccs["retx_timer"]).sum().astype(jnp.int32)
+                        retx_col = retx_col.at[
+                            self._index["tcp_rx"]].set(total)
+                    telem["series"] = series.update(
+                        telem["series"], jnp.stack(pkts_in), jnp.stack(drops),
+                        jnp.stack(bytes_l), retx_col, obs["histo"])
             if self._mirror_cfg is not None:
-                # one fused pack per batch; validity = the recorder's
-                # sample mask, so the mirror obeys the same runtime
-                # obs_ctrl knobs (TRACE_SET) with no retrace.  lax.cond
-                # skips the pack at runtime for batches with no sampled
-                # frame (the common case at production 1/64 sampling).
-                fb = postcard.frame_bytes(nstages)
+                with jax.named_scope("obs/postcard"):
+                    # one fused pack per batch; validity = the recorder's
+                    # sample mask, so the mirror obeys the same runtime
+                    # obs_ctrl knobs (TRACE_SET) with no retrace.  lax.cond
+                    # skips the pack at runtime for batches with no sampled
+                    # frame (the common case at production 1/64 sampling).
+                    fb = postcard.frame_bytes(nstages)
 
-                def _pc_pack(_):
-                    pc, pl = postcard.pack(
-                        self._mirror_cfg, carrier.get("meta"),
-                        telem["step"], fid, E, X, V,
-                        flight.bucket_of(occ), first_reason)
-                    return pc, pl.astype(jnp.int32)
+                    def _pc_pack(_):
+                        pc, pl = postcard.pack(
+                            self._mirror_cfg, carrier.get("meta"),
+                            telem["step"], fid, E, X, V,
+                            flight.bucket_of(occ), first_reason)
+                        return pc, pl.astype(jnp.int32)
 
-                def _pc_skip(_):
-                    return (jnp.zeros((n, fb), jnp.uint8),
-                            jnp.zeros((n,), jnp.int32))
+                    def _pc_skip(_):
+                        return (jnp.zeros((n, fb), jnp.uint8),
+                                jnp.zeros((n,), jnp.int32))
 
-                pc, pclen = jax.lax.cond(sampled.any(), _pc_pack,
-                                         _pc_skip, None)
-                carrier["pc_payload"] = pc
-                carrier["pc_len"] = pclen
-                carrier["pc_valid"] = sampled
+                    pc, pclen = jax.lax.cond(sampled.any(), _pc_pack,
+                                             _pc_skip, None)
+                    carrier["pc_payload"] = pc
+                    carrier["pc_len"] = pclen
+                    carrier["pc_valid"] = sampled
             if self._watchdog_cfg is not None and "slo" in state \
                     and "series" in telem:
-                # rules only re-evaluate on the batch that closed a
-                # window (wr advanced past the watchdog's last look);
-                # edges are rarer still, so the alert pack nests one
-                # level deeper
-                nr = state["slo"]["active"].shape[0]
-                ab = slo.ALERT_BODY_BYTES + postcard.STACK_BYTES
-                fresh = telem["series"]["wr"] > state["slo"]["last_wr"]
+                with jax.named_scope("obs/watchdog"):
+                    # rules only re-evaluate on the batch that closed a
+                    # window (wr advanced past the watchdog's last look);
+                    # edges are rarer still, so the alert pack nests one
+                    # level deeper
+                    nr = state["slo"]["active"].shape[0]
+                    ab = slo.ALERT_BODY_BYTES + postcard.STACK_BYTES
+                    fresh = telem["series"]["wr"] > state["slo"]["last_wr"]
 
-                def _wd_eval(_):
-                    sl, edge, val = slo.evaluate(state["slo"],
-                                                 telem["series"])
+                    def _wd_eval(_):
+                        sl, edge, val = slo.evaluate(state["slo"],
+                                                     telem["series"])
 
-                    def _al_pack(_):
-                        ap, al = slo.alert_frames(
-                            self._watchdog_cfg, sl, telem["series"],
-                            edge, val)
-                        return ap, al.astype(jnp.int32)
+                        def _al_pack(_):
+                            ap, al = slo.alert_frames(
+                                self._watchdog_cfg, sl, telem["series"],
+                                edge, val)
+                            return ap, al.astype(jnp.int32)
 
-                    def _al_skip(_):
-                        return (jnp.zeros((nr, ab), jnp.uint8),
+                        def _al_skip(_):
+                            return (jnp.zeros((nr, ab), jnp.uint8),
+                                    jnp.zeros((nr,), jnp.int32))
+
+                        ap, al = jax.lax.cond(edge.any(), _al_pack,
+                                              _al_skip, None)
+                        return sl, edge, ap, al
+
+                    def _wd_idle(_):
+                        return (state["slo"],
+                                jnp.zeros((nr,), jnp.bool_),
+                                jnp.zeros((nr, ab), jnp.uint8),
                                 jnp.zeros((nr,), jnp.int32))
 
-                    ap, al = jax.lax.cond(edge.any(), _al_pack,
-                                          _al_skip, None)
-                    return sl, edge, ap, al
-
-                def _wd_idle(_):
-                    return (state["slo"],
-                            jnp.zeros((nr,), jnp.bool_),
-                            jnp.zeros((nr, ab), jnp.uint8),
-                            jnp.zeros((nr,), jnp.int32))
-
-                sl, edge, ap, al = jax.lax.cond(fresh, _wd_eval,
-                                                _wd_idle, None)
-                carrier["alert_payload"] = ap
-                carrier["alert_len"] = al
-                carrier["alert_valid"] = edge
-                state["slo"] = sl
+                    sl, edge, ap, al = jax.lax.cond(fresh, _wd_eval,
+                                                    _wd_idle, None)
+                    carrier["alert_payload"] = ap
+                    carrier["alert_len"] = al
+                    carrier["alert_valid"] = edge
+                    state["slo"] = sl
 
         # ---- post-batch table commit (management plane) ------------------
         # A management tile stages table writes in the carrier; they are
@@ -825,52 +856,54 @@ class CompiledPipeline:
         # takes effect on the *next* batch — live reconfiguration with no
         # recompile and no intra-batch ordering hazards (paper §3.6).
         staged = carrier.get("mgmt_staged")
-        if staged is not None:
-            if staged.get("nat") is not None and "nat" in state:
-                state["nat"] = staged["nat"]
-            if staged.get("healthy") and "dispatch" in state:
-                disp = dict(state["dispatch"])
-                for gname, h in staged["healthy"].items():
-                    # only the control-owned field: the batch's rr_counter
-                    # advances stay intact
-                    disp[gname] = dataclasses.replace(disp[gname], healthy=h)
-                state["dispatch"] = disp
-            if staged.get("routes") is not None:
-                state["routes"] = staged["routes"]
-            if staged.get("rate") is not None and "rate" in state:
-                state["rate"] = staged["rate"]
-            if staged.get("cc") is not None and "conn" in state \
-                    and "cc" in state["conn"]:
-                conn = dict(state["conn"])
-                conn["cc"] = staged["cc"]
-                state["conn"] = conn
-            if staged.get("obs_ctrl") is not None and telem is not None \
-                    and "obs" in telem:
-                # recorder knobs are runtime state: TRACE_SET takes effect
-                # next batch, sampling modulus changes with no retrace
-                o = dict(telem["obs"])
-                o["ctrl"] = staged["obs_ctrl"]
-                telem["obs"] = o
-            if staged.get("slo") is not None and "slo" in state:
-                # commit rule fields only — the watchdog's own
-                # active/last_wr/alerts updates from this batch's
-                # evaluation must survive the commit.  A rewritten slot
-                # is unlatched (clear_active) so hysteresis restarts
-                # from the new thresholds.
-                su = staged["slo"]
-                s = dict(state["slo"])
-                for k in ("metric", "node", "thr_raise", "thr_clear",
-                          "enabled"):
-                    s[k] = su[k]
-                s["active"] = jnp.where(su["clear_active"] != 0,
-                                        jnp.zeros_like(s["active"]),
-                                        s["active"])
-                state["slo"] = s
-            if staged.get("series_win") is not None and telem is not None \
-                    and "series" in telem:
-                ser = dict(telem["series"])
-                ser["win_len"] = staged["series_win"]
-                telem["series"] = ser
+        with jax.named_scope("mgmt/commit"):
+            if staged is not None:
+                if staged.get("nat") is not None and "nat" in state:
+                    state["nat"] = staged["nat"]
+                if staged.get("healthy") and "dispatch" in state:
+                    disp = dict(state["dispatch"])
+                    for gname, h in staged["healthy"].items():
+                        # only the control-owned field: the batch's rr_counter
+                        # advances stay intact
+                        disp[gname] = dataclasses.replace(disp[gname],
+                                                          healthy=h)
+                    state["dispatch"] = disp
+                if staged.get("routes") is not None:
+                    state["routes"] = staged["routes"]
+                if staged.get("rate") is not None and "rate" in state:
+                    state["rate"] = staged["rate"]
+                if staged.get("cc") is not None and "conn" in state \
+                        and "cc" in state["conn"]:
+                    conn = dict(state["conn"])
+                    conn["cc"] = staged["cc"]
+                    state["conn"] = conn
+                if staged.get("obs_ctrl") is not None and telem is not None \
+                        and "obs" in telem:
+                    # recorder knobs are runtime state: TRACE_SET takes effect
+                    # next batch, sampling modulus changes with no retrace
+                    o = dict(telem["obs"])
+                    o["ctrl"] = staged["obs_ctrl"]
+                    telem["obs"] = o
+                if staged.get("slo") is not None and "slo" in state:
+                    # commit rule fields only — the watchdog's own
+                    # active/last_wr/alerts updates from this batch's
+                    # evaluation must survive the commit.  A rewritten slot
+                    # is unlatched (clear_active) so hysteresis restarts
+                    # from the new thresholds.
+                    su = staged["slo"]
+                    s = dict(state["slo"])
+                    for k in ("metric", "node", "thr_raise", "thr_clear",
+                              "enabled"):
+                        s[k] = su[k]
+                    s["active"] = jnp.where(su["clear_active"] != 0,
+                                            jnp.zeros_like(s["active"]),
+                                            s["active"])
+                    state["slo"] = s
+                if staged.get("series_win") is not None and telem is not None \
+                        and "series" in telem:
+                    ser = dict(telem["series"])
+                    ser["win_len"] = staged["series_win"]
+                    telem["series"] = ser
         return state, carrier
 
     # ---- streaming execution (device-resident multi-batch) ---------------

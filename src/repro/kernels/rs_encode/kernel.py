@@ -76,6 +76,7 @@ def rs_encode_pallas(data, bitplanes, *, block: int = BLK):
             out_specs=pl.BlockSpec((p, wblk), lambda n: (0, n)),
             out_shape=jax.ShapeDtypeStruct((p, N // 4), jnp.uint32),
             interpret=interpret,
+            name="rs_encode",
         )(words)
 
     return _unpack(select_interpret(call, _pack(data)))

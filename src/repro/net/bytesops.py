@@ -3,6 +3,11 @@
 Payloads are (B, L) uint8 tensors with per-packet valid lengths.  All
 helpers are jittable and operate on whole batches — the TPU analog of the
 FPGA's per-flit header parse/realign datapath.
+
+The header shifts and the checksums run under the named scopes
+``bytes/shift`` and ``bytes/csum``: every HLO op they emit carries the
+scope in its ``op_name`` metadata, so a device trace can say how much of
+each stage's time they take.  Scopes change no computation.
 """
 from __future__ import annotations
 
@@ -67,30 +72,32 @@ def set_be32(payload, off: int, val):
 
 def shift_left(payload, n, mask=None):
     """Strip n leading bytes per packet (n: static int or (B,) int32)."""
-    B, L = payload.shape
-    idx = jnp.arange(L)[None, :]
-    src = idx + (n if isinstance(n, int) else n[:, None])
-    src = jnp.clip(src, 0, L - 1)
-    out = jnp.take_along_axis(payload, src.astype(jnp.int32), axis=1)
-    keep = src < L
-    out = jnp.where(keep, out, 0).astype(jnp.uint8)
-    if mask is not None:
-        out = jnp.where(mask[:, None], out, payload)
-    return out
+    with jax.named_scope("bytes/shift"):
+        B, L = payload.shape
+        idx = jnp.arange(L)[None, :]
+        src = idx + (n if isinstance(n, int) else n[:, None])
+        src = jnp.clip(src, 0, L - 1)
+        out = jnp.take_along_axis(payload, src.astype(jnp.int32), axis=1)
+        keep = src < L
+        out = jnp.where(keep, out, 0).astype(jnp.uint8)
+        if mask is not None:
+            out = jnp.where(mask[:, None], out, payload)
+        return out
 
 
 def shift_right(payload, n, mask=None):
     """Make room for an n-byte header (contents shifted toward the tail)."""
-    B, L = payload.shape
-    idx = jnp.arange(L)[None, :]
-    src = idx - (n if isinstance(n, int) else n[:, None])
-    valid = src >= 0
-    src = jnp.clip(src, 0, L - 1)
-    out = jnp.take_along_axis(payload, src.astype(jnp.int32), axis=1)
-    out = jnp.where(valid, out, 0).astype(jnp.uint8)
-    if mask is not None:
-        out = jnp.where(mask[:, None], out, payload)
-    return out
+    with jax.named_scope("bytes/shift"):
+        B, L = payload.shape
+        idx = jnp.arange(L)[None, :]
+        src = idx - (n if isinstance(n, int) else n[:, None])
+        valid = src >= 0
+        src = jnp.clip(src, 0, L - 1)
+        out = jnp.take_along_axis(payload, src.astype(jnp.int32), axis=1)
+        out = jnp.where(valid, out, 0).astype(jnp.uint8)
+        if mask is not None:
+            out = jnp.where(mask[:, None], out, payload)
+        return out
 
 
 def write_bytes(payload, off: int, data):
@@ -108,18 +115,19 @@ def checksum16(payload, start, length):
     """Ones-complement 16-bit checksum over [start, start+length) per packet.
     start: static int; length: (B,) int32.  Returns (B,) uint32 (already
     complemented, network order)."""
-    B, L = payload.shape
-    idx = jnp.arange(L - start)
-    seg = payload[:, start:].astype(jnp.uint32)
-    valid = idx[None, :] < length[:, None]
-    seg = jnp.where(valid, seg, 0)
-    if seg.shape[1] % 2:
-        seg = jnp.pad(seg, ((0, 0), (0, 1)))
-    words = (seg[:, 0::2] << 8) | seg[:, 1::2]
-    total = words.sum(axis=1, dtype=jnp.uint32)
-    for _ in range(3):                       # fold carries
-        total = (total & 0xFFFF) + (total >> 16)
-    return (~total) & jnp.uint32(0xFFFF)
+    with jax.named_scope("bytes/csum"):
+        B, L = payload.shape
+        idx = jnp.arange(L - start)
+        seg = payload[:, start:].astype(jnp.uint32)
+        valid = idx[None, :] < length[:, None]
+        seg = jnp.where(valid, seg, 0)
+        if seg.shape[1] % 2:
+            seg = jnp.pad(seg, ((0, 0), (0, 1)))
+        words = (seg[:, 0::2] << 8) | seg[:, 1::2]
+        total = words.sum(axis=1, dtype=jnp.uint32)
+        for _ in range(3):                       # fold carries
+            total = (total & 0xFFFF) + (total >> 16)
+        return (~total) & jnp.uint32(0xFFFF)
 
 
 def pseudo_header_sum(src_ip, dst_ip, proto, tcp_len):
@@ -132,18 +140,19 @@ def pseudo_header_sum(src_ip, dst_ip, proto, tcp_len):
 
 def checksum16_with_pseudo(payload, start, length, pseudo):
     """Checksum including a pseudo-header partial sum."""
-    B, L = payload.shape
-    idx = jnp.arange(L - start)
-    seg = payload[:, start:].astype(jnp.uint32)
-    valid = idx[None, :] < length[:, None]
-    seg = jnp.where(valid, seg, 0)
-    if seg.shape[1] % 2:
-        seg = jnp.pad(seg, ((0, 0), (0, 1)))
-    words = (seg[:, 0::2] << 8) | seg[:, 1::2]
-    total = words.sum(axis=1, dtype=jnp.uint32) + pseudo.astype(jnp.uint32)
-    for _ in range(3):
-        total = (total & 0xFFFF) + (total >> 16)
-    return (~total) & jnp.uint32(0xFFFF)
+    with jax.named_scope("bytes/csum"):
+        B, L = payload.shape
+        idx = jnp.arange(L - start)
+        seg = payload[:, start:].astype(jnp.uint32)
+        valid = idx[None, :] < length[:, None]
+        seg = jnp.where(valid, seg, 0)
+        if seg.shape[1] % 2:
+            seg = jnp.pad(seg, ((0, 0), (0, 1)))
+        words = (seg[:, 0::2] << 8) | seg[:, 1::2]
+        total = words.sum(axis=1, dtype=jnp.uint32) + pseudo.astype(jnp.uint32)
+        for _ in range(3):
+            total = (total & 0xFFFF) + (total >> 16)
+        return (~total) & jnp.uint32(0xFFFF)
 
 
 # ---------------------------------------------------------------------------

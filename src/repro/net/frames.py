@@ -11,6 +11,7 @@ import struct
 import numpy as np
 
 from repro.net.bytesops import np_checksum16
+from repro.obs import host
 
 
 def eth_frame(dst_mac: bytes, src_mac: bytes, ethertype: int,
@@ -145,7 +146,14 @@ class FrameArena:
         """Pack a flat list of frames row-major (batch 0 fills first);
         returns the number of batches holding data.  Stale bytes of
         reused slots are cleared so a shorter refill never leaks the
-        previous frame's tail."""
+        previous frame's tail.  Each call is one ``ingress/fill`` span
+        of the program's host counters (``repro.obs.host``)."""
+        with host.span("ingress/fill") as extra:
+            batches = self._fill(frames)
+            extra["frames"] = len(frames)
+        return batches
+
+    def _fill(self, frames) -> int:
         if len(frames) > self.capacity:
             raise ValueError(
                 f"{len(frames)} frames exceed the arena's capacity "

@@ -32,6 +32,7 @@ from jax.sharding import NamedSharding
 from repro.launch.compat import shard_map
 from repro.launch.mesh import make_mesh_for
 from repro.net.frames import FrameArena
+from repro.obs import host
 from repro.sharding import Policy
 
 
@@ -67,26 +68,36 @@ class ShardedFrameArena:
         self.length[:] = 0
 
     def fill_shards(self, frames_per_shard: Sequence[Sequence[bytes]]):
-        """Fill each shard from its own frame list (pre-partitioned)."""
+        """Fill each shard from its own frame list (pre-partitioned).
+        Each call is one ``ingress/fill`` span (``repro.obs.host``)."""
+        with host.span("ingress/fill") as extra:
+            self._fill_shards(frames_per_shard)
+            extra["frames"] = sum(len(f) for f in frames_per_shard)
+
+    def _fill_shards(self, frames_per_shard: Sequence[Sequence[bytes]]):
         if len(frames_per_shard) != self.shards:
             raise ValueError(
                 f"{len(frames_per_shard)} frame lists for "
                 f"{self.shards} shards")
         self.clear()
         for s, frames in enumerate(frames_per_shard):
-            self._views[s].fill(list(frames))
+            self._views[s]._fill(list(frames))
 
     def fill_rss(self, flows: Dict[int, Sequence[bytes]]):
         """Host-side RSS: partition whole *flows* across shards —
         ``flows`` maps a flow key (e.g. the client port) to that flow's
         frames, and every frame of a flow lands on ``key % shards`` so
         per-flow ordering survives the split, exactly like a hardware
-        hash front end.  Returns the per-shard frame counts."""
-        per: List[List[bytes]] = [[] for _ in range(self.shards)]
-        for key, frames in flows.items():
-            per[key % self.shards].extend(frames)
-        self.fill_shards(per)
-        return [len(p) for p in per]
+        hash front end.  Returns the per-shard frame counts.  Each call
+        is one ``ingress/fill`` span (``repro.obs.host``)."""
+        with host.span("ingress/fill") as extra:
+            per: List[List[bytes]] = [[] for _ in range(self.shards)]
+            for key, frames in flows.items():
+                per[key % self.shards].extend(frames)
+            self._fill_shards(per)
+            counts = [len(p) for p in per]
+            extra["frames"] = sum(counts)
+        return counts
 
 
 class ShardedStream:

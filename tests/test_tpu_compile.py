@@ -63,6 +63,18 @@ def test_rs_encode_kernel_compiles_at_serving_width(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+def test_rs_encode_kernel_carries_its_name(one_chip):
+    """The kernel's ``pallas_call`` is named, so the chip's trace names
+    the custom call ``rs_encode`` whatever branch or scope holds it."""
+    bp = gf.bitplane_matrix(gf.generator_matrix(8, 2))
+    hlo = jax.jit(lambda d: rs_encode_pallas(d, bp)).lower(
+        _spec((8, 4096), jnp.uint8, one_chip)).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert calls and all(ln.lstrip().startswith(("%rs_encode",
+                                                 "ROOT %rs_encode"))
+                         for ln in calls)
+
+
 def test_checksum_kernel_compiles_at_arena_width(one_chip):
     hlo = jax.jit(checksum_pallas).lower(
         _spec((256, 1536), jnp.uint8, one_chip),
