@@ -70,34 +70,47 @@ def set_be32(payload, off: int, val):
 # header strip / prepend (data realignment)
 
 
+def _static_shift(payload, s: int, right: bool):
+    """Every row moved s bytes toward the head (or the tail), zero-filled:
+    a slice and a pad."""
+    L = payload.shape[1]
+    s = min(s, L)
+    if right:
+        return jnp.pad(payload[:, :L - s], ((0, 0), (s, 0)))
+    return jnp.pad(payload[:, s:], ((0, 0), (0, s)))
+
+
+def _shift(payload, n, mask, right):
+    """A static n is one slice and pad.  A per-row n is a barrel shifter:
+    for each bit b of n, a select between the rows as they are and the
+    rows moved 2**b bytes, so no per-byte gather.  Every n is exact, and
+    n >= L gives zeros."""
+    L = payload.shape[1]
+    if isinstance(n, int):
+        out = _static_shift(payload, n, right)
+    else:
+        n = jnp.clip(n, 0, L)[:, None]
+        out = payload
+        for b in range(L.bit_length()):
+            out = jnp.where((n >> b) & 1 == 1,
+                            _static_shift(out, 1 << b, right), out)
+    if mask is not None:
+        out = jnp.where(mask[:, None], out, payload)
+    return out
+
+
 def shift_left(payload, n, mask=None):
-    """Strip n leading bytes per packet (n: static int or (B,) int32)."""
+    """Strip n leading bytes per packet (n: static int or (B,) int32); the
+    last n bytes of a row become zero."""
     with jax.named_scope("bytes/shift"):
-        B, L = payload.shape
-        idx = jnp.arange(L)[None, :]
-        src = idx + (n if isinstance(n, int) else n[:, None])
-        src = jnp.clip(src, 0, L - 1)
-        out = jnp.take_along_axis(payload, src.astype(jnp.int32), axis=1)
-        keep = src < L
-        out = jnp.where(keep, out, 0).astype(jnp.uint8)
-        if mask is not None:
-            out = jnp.where(mask[:, None], out, payload)
-        return out
+        return _shift(payload, n, mask, right=False)
 
 
 def shift_right(payload, n, mask=None):
-    """Make room for an n-byte header (contents shifted toward the tail)."""
+    """Make room for an n-byte header (contents shifted toward the tail,
+    the first n bytes zero)."""
     with jax.named_scope("bytes/shift"):
-        B, L = payload.shape
-        idx = jnp.arange(L)[None, :]
-        src = idx - (n if isinstance(n, int) else n[:, None])
-        valid = src >= 0
-        src = jnp.clip(src, 0, L - 1)
-        out = jnp.take_along_axis(payload, src.astype(jnp.int32), axis=1)
-        out = jnp.where(valid, out, 0).astype(jnp.uint8)
-        if mask is not None:
-            out = jnp.where(mask[:, None], out, payload)
-        return out
+        return _shift(payload, n, mask, right=True)
 
 
 def write_bytes(payload, off: int, data):

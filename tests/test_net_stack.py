@@ -1,5 +1,7 @@
 """Network stack: golden-frame interop (Linux wire format), checksums,
 TCP engine behaviour, NAT, RPC framing."""
+import struct
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,6 +40,70 @@ def test_udp_vlan_tagged():
     fr = F.udp_rpc_frame(IP_A, IP_S, 5555, 9000, b"v", vlan=7)
     p, l, m, ok = rx_udp([fr])
     assert bool(ok[0]) and int(l[0]) == 1
+
+
+def _np_strip(rows, n):
+    """Each row without its first n[i] bytes, zero-filled at the tail."""
+    out = np.zeros_like(rows)
+    for i, k in enumerate(n):
+        out[i, :rows.shape[1] - k] = rows[i, k:]
+    return out
+
+
+def _ipv4_with_options(rng, words, payload, bad_csum):
+    """An IPv4 header of ``words`` 32-bit words (options random bytes)."""
+    opts = rng.integers(0, 256, 4 * (words - 5), dtype=np.uint8).tobytes()
+    hdr = struct.pack("!BBHHHBBH", 0x40 | words, 0, 4 * words + len(payload),
+                      0, 0x4000, 64, 17, 0) + struct.pack("!II", IP_A, IP_S)
+    hdr += opts
+    csum = B.np_checksum16(hdr) ^ int(bad_csum)
+    return hdr[:10] + struct.pack("!H", csum) + hdr[12:] + payload
+
+
+def test_vlan_and_ip_options_strip_like_numpy():
+    """eth.parse over frames with and without a VLAN tag, then
+    ipv4.parse_ex over IHL 5-15: each strip, length and verdict equals a
+    numpy strip of the same frames."""
+    rng = np.random.default_rng(7)
+    vlan = rng.random(64) < 0.5
+    words = np.arange(64) % 11 + 5
+    bad = rng.random(64) < 0.2
+    frames = [F.eth_frame(b"\x02" * 6, b"\x04" * 6, eth.ETHERTYPE_IPV4,
+                          _ipv4_with_options(rng, int(w), bytes(
+                              rng.integers(0, 256, 1 + i * 7, np.uint8)), b),
+                          vlan=7 if v else None)
+              for i, (v, w, b) in enumerate(zip(vlan, words, bad))]
+    rows, length = F.to_batch(frames, 640)
+    p, l, meta = eth.parse(jnp.asarray(rows), jnp.asarray(length))
+    hlen = np.where(vlan, eth.VLAN_HLEN, eth.ETH_HLEN)
+    ip_rows = _np_strip(rows, hlen)
+    np.testing.assert_array_equal(np.asarray(p), ip_rows)
+    np.testing.assert_array_equal(np.asarray(l), length - hlen)
+    np.testing.assert_array_equal(np.asarray(meta["vlan_tci"]),
+                                  np.where(vlan, 7, 0))
+    q, ql, _, ok, _ = ipv4.parse_ex(p, l)
+    total = (ip_rows[:, 2].astype(np.int32) << 8) | ip_rows[:, 3]
+    np.testing.assert_array_equal(np.asarray(q), _np_strip(ip_rows, 4 * words))
+    np.testing.assert_array_equal(np.asarray(ql), total - 4 * words)
+    np.testing.assert_array_equal(np.asarray(ok), ~bad)
+
+
+def test_tcp_data_offset_strip_like_numpy():
+    """tcp.parse_segment over data offsets 5-15 (options random bytes):
+    the data and its length equal a numpy strip of the same segments."""
+    rng = np.random.default_rng(8)
+    doff = np.arange(33) % 11 + 5
+    segs = [struct.pack("!HHIIBBHHH", 5555, 80, 1000 + i, 0, int(d) << 4,
+                        F.TCP_ACK, 65535, 0, 0)
+            + rng.integers(0, 256, 4 * (d - 5) + 3 * i, np.uint8).tobytes()
+            for i, d in enumerate(doff)]
+    rows, length = F.to_batch(segs, 192)
+    data, dlen, meta = tcp.parse_segment(jnp.asarray(rows),
+                                         jnp.asarray(length), {})
+    np.testing.assert_array_equal(np.asarray(data), _np_strip(rows, 4 * doff))
+    np.testing.assert_array_equal(np.asarray(dlen), length - 4 * doff)
+    np.testing.assert_array_equal(np.asarray(meta["tcp_seq"]),
+                                  1000 + np.arange(33))
 
 
 def test_corrupted_ip_checksum_dropped():

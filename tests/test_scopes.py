@@ -2,8 +2,9 @@
 
   * every node of the compiled stream program has ops under
     ``stage/<node>``; the byte shifts and checksums land under
-    ``bytes/shift`` and ``bytes/csum``; the executor's observability blocks
-    under ``obs/*`` exist exactly when observability is on;
+    ``bytes/shift`` and ``bytes/csum``, and no shift is a gather; the
+    executor's observability blocks under ``obs/*`` exist exactly when
+    observability is on;
   * the scopes cost nothing: the compiled HLO with its metadata stripped
     (and its instructions numbered in order) is the same with them and
     with ``jax.named_scope`` made a null context;
@@ -88,6 +89,23 @@ def test_every_op_of_a_byte_helper_carries_its_scope(fn, scope):
         jnp.ones((4,), bool)).compile().as_text()
     ops = [n for n in op_names(text) if n.startswith("jit(")]
     assert ops and all(f"/{scope}/" in n for n in ops), ops
+
+
+@pytest.mark.parametrize("fn", [bytesops.shift_left, bytesops.shift_right])
+def test_a_per_row_shift_lowers_to_no_gather(fn):
+    text = jax.jit(fn).lower(
+        jnp.zeros((4, 64), jnp.uint8),
+        jnp.full((4,), 20, jnp.int32)).compile().as_text()
+    assert " gather(" not in text
+
+
+def test_no_header_strip_is_a_gather(compiled):
+    """The eth_rx and ip_rx strips (a per-row VLAN tag, IHL), like every
+    other shift, are slices and selects in the stream program."""
+    kind, _, text = compiled
+    gathers = [n for ln in text.splitlines() if " gather(" in ln
+               for n in op_names(ln)]
+    assert not [n for n in gathers if "/bytes/shift/" in n], gathers
 
 
 def test_obs_scopes_exist_exactly_when_observability_is_on(compiled):
