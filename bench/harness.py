@@ -3,9 +3,9 @@ the plain reference, and the metrics.
 
 Everything a cell needs is found by name: ``BENCHMARK.json`` names the
 cell's configuration and traffic mix, ``bench/configs/<config>.json``
-and ``.py`` build the deployment, frame its requests and hold its
-reference, ``bench/traffic/<mix>.json`` holds the mix (and may name a
-generator of its own, ``bench/traffic/<name>.py``), and
+and ``.py`` build the deployment, frame its requests and judge its
+replies by its reference, ``bench/traffic/<mix>.json`` holds the mix
+(and may name a generator of its own, ``bench/traffic/<name>.py``), and
 ``bench/metrics/<metric>.py`` reads each metric.
 
 The served path is the program's own API.  Each window: the client's
@@ -39,6 +39,7 @@ from bench import traffic
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+CONFIGS = os.path.join(HERE, "configs")
 WINDOW_SPAN = "bench_window"
 SPANS = ("fill", "put", "dispatch", "readback")
 GIVE_UP_S = 60.0             # an answer not back this long after close
@@ -66,9 +67,9 @@ def load_file_module(path: str, name: str):
 
 
 def load_config(name: str):
-    with open(os.path.join(HERE, "configs", f"{name}.json")) as f:
+    with open(os.path.join(CONFIGS, f"{name}.json")) as f:
         cfg = json.load(f)
-    mod = load_file_module(os.path.join(HERE, "configs", f"{name}.py"),
+    mod = load_file_module(os.path.join(CONFIGS, f"{name}.py"),
                            f"bench_config_{name}")
     return cfg, mod
 
@@ -121,7 +122,7 @@ class Window:
     got: Optional[Dict[str, np.ndarray]] = None
     t_reply: float = 0.0
     measured: bool = True
-    ok: Optional[np.ndarray] = None          # (S,) correct replies
+    good: Optional[np.ndarray] = None        # (S, rows) correct replies
 
 
 class Compiles:
@@ -221,8 +222,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
         req = arr % len(pool.frames)
         r = np.full((1, rows), -1, np.int64)
         r[0, :count] = req
-        win = Window(rows=r, arrivals=arr if measured else None,
-                     measured=measured)
+        win = Window(rows=r, arrivals=arr, measured=measured)
         dispatch(win, system.fill_input(pool.frames, pool.client_port, r))
         return win
 
@@ -295,6 +295,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
     states = system.shard_states(box["state"])
     box.clear()
     verdict = check(cfg, cfgmod, system, pool, done, states, cap, due_abs)
+    print(f"judge_s {verdict['judge_s']}", file=log, flush=True)
     verdict["checks"]["compiles_in_window"] = {
         "value": compiles.count, "limit": 0}
 
@@ -308,8 +309,10 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
         frames=int(sum((w.rows >= 0).sum() for w in measured)),
         trace=trace_summary, give_up_s=GIVE_UP_S,
         traced_rows=[(w.rows >= 0).sum(1) for w in traced],
-        traced_ok=[w.ok for w in traced],
-        device_kind=jax.devices()[0].device_kind, **verdict["window"])
+        traced_ok=[w.good.sum(1) for w in traced],
+        traced_windows=[{"rows": w.rows, "good": w.good} for w in traced],
+        pool=pool, device_kind=jax.devices()[0].device_kind,
+        **verdict["window"])
     if keep is not None:
         keep.update(ctx, due=pool.due,
                     reply_times=np.array([w.t_reply for w in measured]),
@@ -394,19 +397,73 @@ def _quiet():
     return opts
 
 
+BUILT_IN = ("reply_mismatch", "reply_missing", "reply_unexpected",
+            "served_gap", "drop_gap", "compiles_in_window")
+
+
+def expected_judge(expected: Callable) -> Callable:
+    """The default judge, for a configuration that defines no ``judge``:
+    each reply's length and bytes against the reply that
+    ``expected(cfg, pool, cap) -> (replies, lengths, body lengths)``
+    builds in advance for every request of the pool."""
+    def judge(cfg, pool, windows, states, cap):
+        exp, exp_len, rlen = expected(cfg, pool, cap)
+        cols = np.arange(cap)
+        wrong, body_len = [], []
+        for win in windows:
+            safe = np.where(win.rows >= 0, win.rows, 0)
+            tx_len = win.got["tx_len"].reshape(safe.shape)
+            payload = win.got["tx_payload"].reshape(safe.shape + (cap,))
+            differs = ((payload != exp[safe])
+                       & (cols < exp_len[safe][..., None])).any(-1)
+            wrong.append((tx_len != exp_len[safe]) | differs)
+            body_len.append(rlen[safe])
+        return {"wrong": wrong, "body_len": body_len}
+    return judge
+
+
 def check(cfg, cfgmod, system, pool, done: List[Window], states, cap,
           due_abs) -> dict:
-    """Compare every reply of the run with the plain reference, and the
-    program's counters with what the reference says they must read."""
+    """Judge every reply of the run against the plain reference, and
+    hold the program's counters to what the traffic says they must read.
+
+    The configuration's ``judge(cfg, pool, windows, states, cap)`` says
+    which replies are wrong; without one, ``expected_judge`` compares
+    bytes with the configuration's ``expected``.  ``windows`` is every
+    window the run dispatched, in dispatch order, set-up's first: each a
+    ``Window`` with ``rows`` ((S, rows) request indices, -1 for an empty
+    row), ``arrivals`` (open loop: the arrival number of each of its
+    first rows, set-up's ``arange(rows)`` too; closed loop: None),
+    ``measured``, and ``got``, the replies as read back (``tx_payload``
+    cut to ``cap`` bytes, ``tx_len``, ``alive``, ``served``).  ``states``
+    is each shard's final state.  The judge returns ``wrong`` and
+    ``body_len``, a list with one (S, rows) array per window: which rows'
+    replies are wrong, and the reply body bytes of each row (for
+    goodput); and may return ``checks``, ``{name: {"value", "limit"}}``,
+    numbers of its own that count toward ``correct`` as the built-in ones
+    do.  The harness alone decides which rows must be answered and which
+    were: a row is mismatched where it was both and the judge calls it
+    wrong.  The harness's own checks (``BUILT_IN``) all have the limit
+    0, and a judge's may not take their names."""
+    judge = getattr(cfgmod, "judge", None) or expected_judge(cfgmod.expected)
+    t_judge = time.perf_counter()
+    judged = judge(cfg, pool, done, states, cap)
+    judge_s = time.perf_counter() - t_judge
+    extra = judged.get("checks", {})
+    if set(extra) & set(BUILT_IN):
+        raise ValueError(f"a judge's checks may not be named "
+                         f"{sorted(set(extra) & set(BUILT_IN))}")
+    if not len(judged["wrong"]) == len(judged["body_len"]) == len(done):
+        raise ValueError("a judge must give one array per window")
     ok = pool.kind == traffic.OK
-    exp, exp_len, rlen = cfgmod.expected(cfg, pool, cap)
     S = system.shards
     missing = unexpected = mismatch = 0
     served_want = np.zeros(S, np.int64)
     kinds_want = np.zeros((S, 3), np.int64)
     w_ok = w_body = w_attempted = w_failed = 0
     lat = []
-    for win in done:
+    for win, judged_wrong, rlen in zip(done, judged["wrong"],
+                                       judged["body_len"]):
         idx = win.rows
         live = idx >= 0
         safe = np.where(live, idx, 0)
@@ -414,14 +471,8 @@ def check(cfg, cfgmod, system, pool, done: List[Window], states, cap,
         got = win.got
         answered = got["alive"].reshape(idx.shape) & \
             got["served"].reshape(idx.shape)
-        tx_len = got["tx_len"].reshape(idx.shape)
-        payload = got["tx_payload"].reshape(idx.shape + (cap,))
         both = want & answered
-        wrong = both & (tx_len != exp_len[safe])
-        cols = np.arange(cap)
-        differs = ((payload != exp[safe])
-                   & (cols < exp_len[safe][..., None])).any(-1)
-        wrong |= both & differs
+        wrong = both & judged_wrong
         miss_w = want & ~answered
         extra_w = answered & ~want
         missing += int(miss_w.sum())
@@ -431,14 +482,14 @@ def check(cfg, cfgmod, system, pool, done: List[Window], states, cap,
         for k in range(3):
             kinds_want[:, k] += (live & (pool.kind[safe] == k)).sum(1)
         good = both & ~wrong
-        win.ok = good.sum(1)
+        win.good = good
         if not win.measured:
             continue
         bad = miss_w | extra_w | wrong
         w_attempted += int(live.sum())
         w_failed += int(bad.sum())
         w_ok += int(good.sum())
-        w_body += int(np.where(good, rlen[safe], 0).sum())
+        w_body += int(np.where(good, rlen, 0).sum())
         if win.arrivals is not None:
             req_ok = good[0, :len(win.arrivals)]
             t = (win.t_reply - due_abs[win.arrivals])
@@ -461,12 +512,14 @@ def check(cfg, cfgmod, system, pool, done: List[Window], states, cap,
         "reply_unexpected": {"value": unexpected, "limit": 0},
         "served_gap": {"value": served_gap, "limit": 0},
         "drop_gap": {"value": drop_gap, "limit": 0},
+        **extra,
     }
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     window = {"attempted": w_attempted, "failed": w_failed,
               "replies_ok": w_ok, "reply_body_bytes": w_body,
               "latency_s": np.concatenate(lat) if lat else None}
-    return {"correct": correct, "checks": checks, "window": window}
+    return {"correct": correct, "checks": checks, "window": window,
+            "judge_s": judge_s}
 
 
 def _drop_gap(cfg, system, state, kinds) -> int:

@@ -7,7 +7,11 @@ then leaves the metric out of the result line.
 Device time per unit of work comes from the stream program's runs that
 started and ended inside the traced span (``Summary.runs``): run r of a
 device served the traced window r (``ctx["traced_rows"][r]``, the rows
-each shard held; ``ctx["traced_ok"][r]``, the correct replies).
+each shard held; ``ctx["traced_ok"][r]``, the correct replies).  A
+metric that counts a configuration's own work in a window (tokens,
+bytes) finds it in ``ctx["pool"]``, the run's requests, and
+``ctx["traced_windows"][r]``: window r's ``rows`` (request indices, -1
+for an empty row) and ``good`` (which rows got a correct reply).
 """
 from __future__ import annotations
 

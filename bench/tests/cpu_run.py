@@ -25,15 +25,29 @@ SMALL_MIX = {"rs.open": {"rate_per_s": 400.0}}
 
 
 def run(cell: str, fault: str = None, seed: int = 2**33 + 17,
-        seconds: float = 0.05, keep: dict = None) -> dict:
-    bench = dict(harness.load_benchmark(), workloads=[
-        {"name": n, "config": c, "traffic": t, "chips": 1}
-        for n, (c, t) in CELLS.items()])
+        seconds: float = 0.05, keep: dict = None, bench: dict = None,
+        log=None) -> dict:
+    """Run ``cell`` of the table above, or else of ``bench``
+    (``BENCHMARK.json`` by default), at its small sizes: a cell outside
+    the table takes them from its configuration module's ``CPU_SMALL``
+    and, where it has one, ``CPU_SMALL_MIX``."""
+    bench = bench or harness.load_benchmark()
+    cells = [{"name": n, "config": c, "traffic": t, "chips": 1}
+             for n, (c, t) in CELLS.items()]
+    if cell in CELLS:
+        small, small_mix = SMALL[cell], SMALL_MIX.get(cell)
+    else:
+        wl = {w["name"]: w for w in bench["workloads"]}[cell]
+        _, mod = harness.load_config(wl["config"])
+        small = mod.CPU_SMALL
+        small_mix = getattr(mod, "CPU_SMALL_MIX", None)
+        cells.append(wl)
     return harness.run_cell(
-        cell, seed, seconds, False, time.perf_counter(), bench=bench,
-        overrides=SMALL[cell], mix_overrides=SMALL_MIX.get(cell),
+        cell, seed, seconds, False, time.perf_counter(),
+        bench=dict(bench, workloads=cells), overrides=small,
+        mix_overrides=small_mix,
         fault=faults.FAULTS[fault] if fault else None, keep=keep,
-        log=io.StringIO())
+        log=log or io.StringIO())
 
 
 def assert_sound(out: dict):

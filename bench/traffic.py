@@ -34,8 +34,12 @@ work and not its amount.
 
 The generator says what the client sends: which flow, how long a body,
 which bytes, whether it is broken and when it is due.  The configuration
-frames it (``requests``) and builds the replies it expects
-(``expected``), so a new protocol is a configuration of its own.
+frames it (``requests``) and judges the replies: by default byte for byte
+against replies it builds in advance (``expected``), or with a ``judge``
+of its own where a reply can only be judged once it is seen, as a served
+model's tokens are, by the reference's logits teacher-forced on them
+(``harness.check`` gives the contract).  So a new protocol is a
+configuration of its own.
 """
 from __future__ import annotations
 
